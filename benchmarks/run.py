@@ -60,6 +60,8 @@ def main(argv=None):
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args(argv)
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     failures = 0
     for name, desc in BENCHES:

@@ -15,8 +15,9 @@ Three assertions:
   3. *QPS guardrail*: the two-stage select plane ("fused_ref", the jnp
      engine this CPU container actually runs) is not slower than the
      full-materialize plane beyond a generous floor.  (The Pallas "fused"
-     kernel itself is compiled only on TPU; in CPU interpret mode it is a
-     correctness artifact, not a speed one, so it is excluded from timing.)
+     kernel itself is compiled only on TPU; on the CPU it runs as
+     "fused_interpret", a correctness artifact, not a speed one, so it is
+     excluded from timing.)
 
   PYTHONPATH=src python -m benchmarks.scan_select [--quick]
 """
@@ -71,7 +72,8 @@ def _assert_no_gather(st, q):
     planner._gather_probed_panels = counting
     try:
         # unique pool statics force fresh traces (the gather is trace-time)
-        st.search(q, topk=10, mode="B", pool=37, scan_impl="fused")
+        st.search(q, topk=10, mode="B", pool=37,
+                  scan_impl="fused_interpret")
         fused_calls = len(calls)
         st.search(q, topk=10, mode="B", pool=39, scan_impl="ref")
         ref_calls = len(calls) - fused_calls

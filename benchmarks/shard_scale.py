@@ -12,8 +12,9 @@ forced host devices carve one CPU into n logical devices that share the
 same cores, so sharding pays collective overhead without adding FLOPs.  On
 real multi-chip meshes the per-shard work column is the wall-clock story.
 
-Runs in a subprocess with forced host devices (the device count must be
-fixed before jax initializes):
+Runs in a subprocess on the CPU with forced host devices (the device
+count must be fixed before jax initializes, and the child must never touch
+the accelerator its parent process may already hold):
 
   PYTHONPATH=src python -m benchmarks.shard_scale [--quick]
 """
@@ -100,9 +101,10 @@ def _child(quick: bool):
 
 
 def main(quick: bool = False):
-    """Spawn the sweep with 8 forced host devices (fresh jax)."""
+    """Spawn the sweep with 8 forced host devices (fresh jax, CPU only)."""
     print("shards, qps, probed grains/shard, scan slots/shard")
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")

@@ -7,8 +7,8 @@ into three explicit stages with per-stage survivor budgets ``(b1, b2)``:
   stage 1 — §2.2 sketch/residual filter: every probed slot is priced at
     the cheap remainder of the scan distance (residual energy term + query
     residual + sketch term — everything EXCEPT the coordinate term, which
-    is >= 0).  The pricing runs through the existing select machinery on a
-    zero-width coordinate panel, so the PR 4 kernel's scalar-prefetch
+    is >= 0).  The pricing runs through the existing select machinery with
+    the coordinate term left out, so the PR 4 kernel's scalar-prefetch
     streaming and in-VMEM running top-k carry the stage for free: only the
     top-``b1`` flat slots survive, and the [Q, P*cap] matrix never exists.
   stage 2 — quantized tangent-coordinate distance: the b1 survivors'
@@ -61,19 +61,20 @@ def check_budgets(budgets, topk: int) -> None:
 def _stage1_filter(engine: str, gids, rq, keep, res, mask, scale, res_scale,
                    sq, sketch, sketch_scale, tenant_mask, tenant_ix,
                    b1: int, n_active=None):
-    """Stage 1: cheap filter over every probed slot via a zero-k panel.
+    """Stage 1: cheap filter over every probed slot, coordinate term left
+    out.
 
     The scan distance is  coord_term + res*res_scale + rq (+ sketch_term)
-    with coord_term >= 0, so scanning a zero coordinate panel (k=1, all
-    zeros, query coords 0) prices each slot at exactly the cheap remainder.
-    Every mask (validity/liveness/tag/ts/envelope/tenant) is applied by the
-    underlying select engine.  Returns (d1 [Q, b1] f32 ascending,
-    fs [Q, b1] i32 flat slots g*cap + c, -1 = pruned).
+    with coord_term >= 0, so pricing a slot without it gives exactly the
+    cheap remainder.  The kernel engines drop the term statically; the jnp
+    oracle scans a zero coordinate panel (k=1, all zeros, query coords 0),
+    which gives the same floats.  Every mask (validity/liveness/tag/ts/
+    envelope/tenant) is applied by the underlying select engine.  Returns
+    (d1 [Q, b1] f32 ascending, fs [Q, b1] i32 flat slots g*cap + c,
+    -1 = pruned).
     """
     g_n, cap = res.shape
     q_n, p_n = gids.shape
-    zq1 = jnp.zeros((q_n, p_n, 1), jnp.int32)
-    z1 = jnp.zeros((g_n, 1, cap), jnp.int16)
     fsl = (jnp.arange(g_n, dtype=jnp.int32)[:, None] * cap
            + jnp.arange(cap, dtype=jnp.int32)[None, :])
     kw = {}
@@ -85,20 +86,25 @@ def _stage1_filter(engine: str, gids, rq, keep, res, mask, scale, res_scale,
         # ride the ragged-probe stream too: the keep fold already kills the
         # probes semantically, n_active= additionally dedupes their DMAs
         kw["n_active"] = n_active
-    runner = fused_scan_select if engine == "kernel" \
-        else scan.blocksoa_select_ref
-    return runner(gids, zq1, rq, keep, z1, res, mask, fsl, scale, res_scale,
-                  width=b1, **kw)
+    if engine == "ref":
+        zq1 = jnp.zeros((q_n, p_n, 1), jnp.int32)
+        z1 = jnp.zeros((g_n, 1, cap), jnp.int16)
+        return scan.blocksoa_select_ref(gids, zq1, rq, keep, z1, res, mask,
+                                        fsl, scale, res_scale, width=b1, **kw)
+    return fused_scan_select(gids, None, rq, keep, None, res, mask, fsl,
+                             scale, res_scale, width=b1,
+                             interpret=engine == "interpret", **kw)
 
 
 def make_cascade_runner(stage1_engine: str):
     """Build a select-plane runner for the cascade backend.
 
     stage1_engine: "kernel" — stage 1 rides the fused scalar-prefetch
-    Pallas kernel (compiled on TPU, interpret elsewhere); "ref" — stage 1
-    uses the jnp two-stage-select oracle (fast CPU parity path).
+    Pallas kernel, compiled for the TPU; "interpret" — the same kernel in
+    the Pallas interpreter (CPU validation); "ref" — stage 1 uses the jnp
+    two-stage-select oracle (fast CPU parity path).
     """
-    assert stage1_engine in ("kernel", "ref"), stage1_engine
+    assert stage1_engine in ("kernel", "interpret", "ref"), stage1_engine
 
     def cascade_select(gids, zq, rq, keep, coords, res, mask, rows, scale,
                        res_scale, sq=None, sketch=None, sketch_scale=None, *,

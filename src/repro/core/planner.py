@@ -594,8 +594,6 @@ def search_stacked_sharded(plane: ShardedStackedSegments, q: jax.Array, *,
     the shard_map body is one fixed-shape program; killed probes are
     masked (and their panel DMAs deduped by the ragged kernel) in place.
     """
-    from ..distributed.sharding import SHARD_MAP_CHECK_KW, shard_map
-
     adaptive = probe_margin is not None and not math.isinf(probe_margin)
 
     n_shards = mesh.shape[grain_axis]
@@ -663,8 +661,8 @@ def search_stacked_sharded(plane: ShardedStackedSegments, q: jax.Array, *,
                 _spec_tree(tenant_live, P(None, grain_axis)),
                 _spec_tree(tenant_ix, q_spec),
                 _spec_tree(hub_mask, P(grain_axis)))
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs,
-                   out_specs=(q_spec, q_spec), **{SHARD_MAP_CHECK_KW: False})
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=(q_spec, q_spec), check_vma=False)
     ids, d = fn(plane.index, plane.gid_of_row, plane.live, q, tag_mask,
                 ts_range, tenant_live, tenant_ix, hub_mask)
     return SearchResult(ids=ids, dists=d)

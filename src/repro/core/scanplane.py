@@ -17,15 +17,22 @@ Registered backends:
   "ref"         gather   pure-jnp Block-SoA oracle (XLA-fused; CPU default)
   "pallas"      gather   Pallas scan kernels, compiled (TPU)
   "interpret"   gather   same kernels, interpreter mode (CPU validation)
-  "fused"       select   scalar-prefetch fused scan→select Pallas kernel
-                         (compiled on TPU, interpret elsewhere)
+  "fused"       select   scalar-prefetch fused scan→select Pallas kernel,
+                         compiled for the TPU
+  "fused_interpret"
+                select   the same kernel, interpreter mode (CPU validation)
   "fused_ref"   select   jnp two-stage-select oracle of the fused kernel
   "cascade"     select   mixed-precision 3-stage cascade (sketch filter →
                          quantized re-price → exact re-rank), staged:
                          accepts budgets=(b1, b2); stage 1 on the fused
-                         kernel
+                         kernel, compiled for the TPU
+  "cascade_interpret"
+                select   the cascade with stage 1 on the interpreted kernel
   "cascade_ref" select   the cascade with stage 1 on the jnp oracle
   "auto"        —        "fused" on TPU, "ref" elsewhere
+
+A compiled kernel never falls back to the interpreter or to a reference:
+interpreter mode is chosen only by its name.
 
 Every planner entry point and ``VectorStore.search`` accept the backend by
 name (``scan_impl=...``); the name is a jit static, and the store keys its
@@ -37,6 +44,7 @@ external accelerator engine).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
@@ -125,8 +133,13 @@ register_scan_plane(
 register_scan_plane(
     "fused", SELECT, fused_scan_select,
     "scalar-prefetch fused scan→select kernel: gather-free panel "
-    "streaming + in-VMEM running top-k (compiled on TPU, interpret "
-    "elsewhere)", adaptive=True)
+    "streaming + in-VMEM running top-k, compiled for the TPU",
+    adaptive=True)
+register_scan_plane(
+    "fused_interpret", SELECT,
+    functools.partial(fused_scan_select, interpret=True),
+    "the fused scan→select kernel in interpreter mode — validates the "
+    "exact TPU kernel body on CPU", adaptive=True)
 register_scan_plane(
     "fused_ref", SELECT, scan.blocksoa_select_ref,
     "jnp two-stage-select oracle of the fused kernel (CPU oracle for the "
@@ -134,9 +147,14 @@ register_scan_plane(
 register_scan_plane(
     "cascade", SELECT, cascade.make_cascade_runner("kernel"),
     "mixed-precision cascade: §2.2 sketch/residual filter (stage 1, the "
-    "fused kernel on a zero-k panel) → quantized tangent-coord re-price of "
-    "the b1 survivors (stage 2) → exact raw re-rank (stage 3, the shared "
-    "epilogue); accepts budgets=(b1, b2)", staged=True, adaptive=True)
+    "fused kernel without the coordinate term) → quantized tangent-coord "
+    "re-price of the b1 survivors (stage 2) → exact raw re-rank (stage 3, "
+    "the shared epilogue); accepts budgets=(b1, b2)", staged=True,
+    adaptive=True)
+register_scan_plane(
+    "cascade_interpret", SELECT, cascade.make_cascade_runner("interpret"),
+    "the cascade with stage 1 on the interpreted fused kernel (CPU "
+    "validation of the staged contract)", staged=True, adaptive=True)
 register_scan_plane(
     "cascade_ref", SELECT, cascade.make_cascade_runner("ref"),
     "the cascade with stage 1 on the jnp select oracle (fast CPU parity "

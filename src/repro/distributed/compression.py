@@ -22,9 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .sharding import SHARD_MAP_CHECK_KW as _SM_CHECK
-from .sharding import shard_map as _shard_map
-
 
 def _psum_bf16(g, axis):
     return jax.lax.psum(g.astype(jnp.bfloat16), axis).astype(jnp.float32)
@@ -88,8 +85,8 @@ def make_compressed_train_step(model, optimizer, mesh: Mesh, *,
             jax.tree_util.tree_map(batch_spec, batch),
         )
         out_specs = (in_specs[0], in_specs[1], in_specs[2], rep)
-        fn = _shard_map(local_step, mesh=mesh, in_specs=in_specs,
-                        out_specs=out_specs, **{_SM_CHECK: False})
+        fn = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return fn(params, opt_state, err, batch)
 
     def init_error(params):

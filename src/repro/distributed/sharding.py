@@ -31,17 +31,6 @@ from typing import Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# Single compat point for the explicit-collective API: jax >= 0.5/0.6 exposes
-# a stable jax.shard_map with a `check_vma` kwarg, older releases the
-# experimental one with `check_rep`.  Callers pass the check kwarg as
-# **{SHARD_MAP_CHECK_KW: flag}.
-try:
-    shard_map = jax.shard_map
-    SHARD_MAP_CHECK_KW = "check_vma"
-except AttributeError:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-    SHARD_MAP_CHECK_KW = "check_rep"
-
 
 @dataclasses.dataclass(frozen=True)
 class ShardingRules:

@@ -5,10 +5,12 @@ lifted to query-batched matmul form so the MXU does the heavy lifting —
 
     D_int[Q, B] = ||zq||^2 1^T + 1 ||z_i||^2^T - 2 * Zq @ Z^T
 
-with int16 coordinates widened to int32 inside VMEM and int32 accumulation
-(`preferred_element_type=int32`), exact because quantization is int32-safe
-(core/index.int32_safe_qmax).  Per-grain scales and residual terms are fused
-into the epilogue, as is the validity / mixed-recall mask — the paper's
+exact in int32 because quantization is int32-safe
+(core/index.int32_safe_qmax).  Mosaic has no int32 matmul, so the cross
+term splits each operand into 7-bit limbs that bf16 holds exactly and runs
+four bf16 MXU products with f32 accumulation (exact below 2^24), recombined
+in int32 (``_exact_int_dot``).  Per-grain scales and residual terms are
+fused into the epilogue, as is the validity / mixed-recall mask — the paper's
 "in-situ predicate check inside the scan loop".
 
 Layout: the coordinate panel arrives dimension-major `[k, cap]` (Block-SoA);
@@ -42,6 +44,32 @@ def _query_block(q: int) -> int:
     return min(BLK_Q, -(-q // 8) * 8)
 
 
+def _limbs(x):
+    """int32 x -> bf16 (hi, lo) with x == hi * 128 + lo, lo in [0, 128).
+    Exact in bf16 (8 significant bits) for |x| <= 2^15, i.e. any int16."""
+    hi = jnp.right_shift(x, 7)
+    lo = jnp.bitwise_and(x, 127)
+    return (hi.astype(jnp.float32).astype(jnp.bfloat16),
+            lo.astype(jnp.float32).astype(jnp.bfloat16))
+
+
+def _exact_int_dot(a, b):
+    """Exact int32 ``a @ b`` for int16-range a [M, k], b [k, N], k <= 256,
+    on the MXU: every limb product is < 2^16 and every f32 partial sum of
+    k of them stays below 2^24, so each of the four products is exact."""
+    assert a.shape[1] <= 256, "limb partial sums must stay below 2^24"
+    a_hi, a_lo = _limbs(a)
+    b_hi, b_lo = _limbs(b)
+
+    def dot(x, y):
+        return jax.lax.dot_general(
+            x, y, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    return (dot(a_hi, b_hi) * 16384
+            + (dot(a_hi, b_lo) + dot(a_lo, b_hi)) * 128 + dot(a_lo, b_lo))
+
+
 def _scan_kernel(zq_ref, rq_ref, coords_ref, res_ref, valid_ref,
                  scale_ref, res_scale_ref, out_ref):
     """One (grain g, query tile qi, cap tile ci) cell.
@@ -57,11 +85,7 @@ def _scan_kernel(zq_ref, rq_ref, coords_ref, res_ref, valid_ref,
     """
     zq = zq_ref[...]                                   # i32 [BLK_Q, k]
     panel = coords_ref[...].astype(jnp.int32)          # [k, BLK_C]
-
-    # MXU cross term with exact int32 accumulation.
-    cross = jax.lax.dot_general(
-        zq, panel, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)              # [BLK_Q, BLK_C]
+    cross = _exact_int_dot(zq, panel)                  # [BLK_Q, BLK_C]
     zq2 = jnp.sum(zq * zq, axis=1, keepdims=True)      # [BLK_Q, 1]
     zi2 = jnp.sum(panel * panel, axis=0, keepdims=True)  # [1, BLK_C]
     d_int = zq2 + zi2 - 2 * cross                      # exact int32
@@ -78,7 +102,7 @@ def _scan_kernel(zq_ref, rq_ref, coords_ref, res_ref, valid_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def hntl_scan(zq, rq, coords, res, valid, scale, res_scale, *,
-              interpret: bool = True):
+              interpret: bool = False):
     """Batched-query Block-SoA scan over P grain panels.
 
     Args (P grains, Q queries, k dims, cap slots; Q % BLK_Q == 0 handled by
@@ -164,7 +188,7 @@ def _scan_single_kernel(zq_ref, rq_ref, coords_ref, res_ref, valid_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def hntl_scan_single(zq, rq, coords, res, valid, scale, res_scale, *,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """Single-query Block-SoA scan over P independent grain panels.
 
     zq [P, k] i32, rq [P] f32, coords [P, k, cap] i16, res [P, cap] i32,

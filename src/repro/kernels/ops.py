@@ -2,11 +2,11 @@
 
 Backend policy:
   - "pallas"  : pl.pallas_call, compiled for TPU (interpret=False).
-  - "interpret": same kernel body executed in Python on CPU — the
-    correctness-validation mode used by tests on this container.
-  - "ref"     : pure-jnp oracle (XLA-fused); the default on CPU where it is
-    both the fastest and the semantics reference.
+  - "interpret": same kernel body run by the Pallas interpreter — the CPU
+    correctness-validation mode, only ever chosen by name.
+  - "ref"     : pure-jnp oracle (XLA-fused); the semantics reference.
   - "auto"    : pallas on TPU, ref elsewhere.
+Any other name is an error.
 
 The sketch term (paper §2.2 s-dim residual sketch) is folded in by a second
 kernel pass over the int8 sketch panels: Eq. 6 extends to
@@ -30,14 +30,19 @@ def default_backend() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
+_BACKENDS = {"pallas": ("pallas", False), "interpret": ("pallas", True),
+             "ref": ("ref", False)}
+
+
 def _resolve(backend: str):
     if backend == "auto":
         backend = default_backend()
-    if backend == "pallas":
-        return "pallas", False
-    if backend == "interpret":
-        return "pallas", True
-    return "ref", False
+    try:
+        return _BACKENDS[backend]
+    except KeyError:
+        raise ValueError(
+            f"unknown scan backend {backend!r}; expected one of "
+            f"{sorted(_BACKENDS) + ['auto']}") from None
 
 
 def scan_batched(zq, rq, coords, res, valid, scale, res_scale,

@@ -23,6 +23,7 @@ from ..core import HNTLConfig, scan_plane_names
 from ..core.store import VectorStore
 from ..models import get_model
 from ..serve.engine import ServeEngine
+from .cache import use_compile_cache
 from .mesh import make_search_mesh
 
 
@@ -91,6 +92,7 @@ def main(argv=None):
     ap.add_argument("--tenant-budget", type=int, default=256,
                     help="per-tenant memtable row budget (overflow seals)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     budgets = None
     if args.budgets is not None:
         try:

@@ -62,6 +62,15 @@ def pytest_configure(config):
         _install_sanitizer()
 
 
+def pytest_make_parametrize_id(config, val, argname):
+    """CPU tests reach the Pallas select kernels through their interpreter
+    planes ("fused_interpret", "cascade_interpret"); their test ids keep
+    the plane's own name ("fused", "cascade")."""
+    if isinstance(val, str) and val.endswith("_interpret"):
+        return val[:-len("_interpret")]
+    return None
+
+
 @pytest.fixture(scope="session")
 def rng():
     return np.random.default_rng(0)

@@ -34,10 +34,10 @@ from repro.core.types import BIG
 
 D, SEG_ROWS, N_SEG = 24, 128, 3
 
-# "pallas"/"cascade" compiled need TPU; on CPU their kernel bodies run in
-# interpreter mode (same registry rule as test_scan_plane.py)
-BACKENDS = ["ref", "interpret", "fused", "fused_ref"]
-CASCADES = ["cascade", "cascade_ref"]
+# compiled kernels need TPU; on CPU their kernel bodies run in interpreter
+# mode, asked for by name (same registry rule as test_scan_plane.py)
+BACKENDS = ["ref", "interpret", "fused_interpret", "fused_ref"]
+CASCADES = ["cascade_interpret", "cascade_ref"]
 
 
 def _cfg():

@@ -38,7 +38,8 @@ from repro.core.store import VectorStore, stack_segments
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 D, SEG_ROWS, N_SEG = 24, 128, 2
-CASCADES = ["cascade", "cascade_ref"]
+# the kernel-backed cascade runs interpreted on CPU, asked for by name
+CASCADES = ["cascade_interpret", "cascade_ref"]
 CASES = [dict(), dict(tag_mask=2), dict(ts_range=(0.0, 1.0)),
          dict(tag_mask=1, ts_range=(0.0, 2.0))]
 
@@ -151,7 +152,7 @@ def test_cascade_never_gathers_probed_panels(store, monkeypatch):
 
     monkeypatch.setattr(planner, "_gather_probed_panels", poisoned)
     st.search(q, topk=7, mode="B", pool=39, scan_impl="cascade_ref")
-    st.search(q, topk=7, mode="B", pool=39, scan_impl="cascade",
+    st.search(q, topk=7, mode="B", pool=39, scan_impl="cascade_interpret",
               budgets=(128, 16))
 
 
@@ -278,7 +279,8 @@ def test_sharded_parity_forced_4_devices(bit_alloc):
                                     mesh=mesh, **case)
                     _assert_same(res, ref)
             ref0 = st.search(q, topk=5, mode="B", scan_impl="ref", mesh=mesh)
-            resb = st.search(q, topk=5, mode="B", scan_impl="cascade",
+            resb = st.search(q, topk=5, mode="B",
+                             scan_impl="cascade_interpret",
                              mesh=mesh, budgets=(4096, 32))
             _assert_same(resb, ref0)
         print("OK")

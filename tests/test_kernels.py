@@ -183,3 +183,90 @@ def test_invalid_slot_sentinel_is_single_sourced():
     assert ops.NEG_BIG == BIG
     assert kfsel.NEG_BIG == BIG
     assert kv.BIG == BIG
+
+
+# ---------------------------------------------------------------------------
+# Fused scan→select kernel: the select is exact, ties in lax.top_k order
+# ---------------------------------------------------------------------------
+
+
+def _select_args(seed, q=5, p=4, g=9, k=4, cap=200, s=0, ties=True):
+    """Select-plane inputs; ``ties`` draws tiny integer ranges so many
+    slots share a distance (the tie order is what is pinned)."""
+    r = np.random.default_rng(seed)
+    mag = 3 if ties else 1000
+    gids = np.stack([r.choice(g, p, replace=False) for _ in range(q)])
+    a = [gids.astype(np.int32),
+         r.integers(-mag, mag + 1, (q, p, k)).astype(np.int32),
+         (r.integers(0, 3, (q, p)) if ties else r.random((q, p)))
+         .astype(np.float32),
+         r.random((q, p)) > 0.15,
+         r.integers(-mag, mag + 1, (g, k, cap)).astype(np.int16),
+         r.integers(0, 3 if ties else 100, (g, cap)).astype(np.int32),
+         r.random((g, cap)) > 0.2,
+         r.permutation(g * cap).reshape(g, cap).astype(np.int32),
+         (np.ones(g) if ties else r.random(g) + 0.5).astype(np.float32),
+         np.ones(g, np.float32)]
+    if s:
+        a += [r.integers(-3, 4, (q, p, s)).astype(np.int32),
+              r.integers(-3, 4, (g, s, cap)).astype(np.int8),
+              np.ones(g, np.float32)]
+    n_active = r.integers(1, p + 1, q).astype(np.int32)
+    return [jnp.asarray(v) for v in a], jnp.asarray(n_active)
+
+
+def _assert_select_equal(got, want):
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+@pytest.mark.parametrize("width", [1, 7, 20, 130, 300])
+@pytest.mark.parametrize("sketch", [0, 3])
+@pytest.mark.parametrize("ragged", [False, True])
+@pytest.mark.parametrize("ties", [True, False])
+def test_fused_select_tie_order_matches_oracle(width, sketch, ragged, ties):
+    """The kernel's carried-set select keeps lax.top_k's order, earliest
+    (probe, slot) first on equal distances, so the interpreted kernel and
+    the jnp two-stage oracle ("fused_ref") agree bit for bit — widths below
+    a tile, at the pool, and over several lane tiles."""
+    from repro.core.scan import blocksoa_select_ref
+    from repro.kernels.fused_select import fused_scan_select
+    args, n_active = _select_args(width + sketch, s=sketch, ties=ties)
+    kw = dict(n_active=n_active) if ragged else {}
+    got = fused_scan_select(*args, width=width, interpret=True, **kw)
+    _assert_select_equal(got, blocksoa_select_ref(*args, width=width, **kw))
+
+
+def test_fused_select_without_coords_matches_zero_panel():
+    """``coords=None`` (the cascade's stage 1) prices slots exactly like a
+    scan of an all-zero coordinate panel."""
+    from repro.core.scan import blocksoa_select_ref
+    from repro.kernels.fused_select import fused_scan_select
+    a, _ = _select_args(3, s=3)
+    got = fused_scan_select(a[0], None, a[2], a[3], None, *a[5:], width=40,
+                            interpret=True)
+    zq0 = jnp.zeros(a[1].shape[:2] + (1,), jnp.int32)
+    z0 = jnp.zeros((a[4].shape[0], 1, a[4].shape[2]), jnp.int16)
+    want = blocksoa_select_ref(a[0], zq0, a[2], a[3], z0, *a[5:], width=40)
+    _assert_select_equal(got, want)
+
+
+def test_fused_select_splits_query_axis(monkeypatch):
+    """A batch whose flat prefetch streams would overflow SMEM runs as
+    several kernel calls along the query axis, with identical results."""
+    from repro.core.scan import blocksoa_select_ref
+    from repro.kernels import fused_select as kfsel
+    a, n_active = _select_args(5, q=7, p=4)
+    want = blocksoa_select_ref(*a, width=20, n_active=n_active)
+    monkeypatch.setattr(kfsel, "PREFETCH_ENTRIES", 8)   # 2 queries a call
+    got = kfsel.fused_scan_select.__wrapped__(*a, width=20, interpret=True,
+                                              n_active=n_active)
+    _assert_select_equal(got, want)
+
+
+def test_kernel_backend_names_are_never_guessed():
+    """An unknown backend name is an error, not the reference."""
+    with pytest.raises(ValueError, match="unknown scan backend"):
+        ops._resolve("palas")
+    assert ops._resolve("interpret") == ("pallas", True)
+    assert ops._resolve("pallas") == ("pallas", False)

@@ -365,7 +365,7 @@ def test_compact_runs_maintenance_and_flag_disables_it():
     assert [id(s) for s in st._segments] != segs0
 
 
-@pytest.mark.parametrize("backend", ["ref", "interpret", "fused",
+@pytest.mark.parametrize("backend", ["ref", "interpret", "fused_interpret",
                                      "fused_ref"])
 def test_maintained_plane_serves_every_scan_backend(backend):
     """Post-maintenance planes answer identically through every ScanPlane

@@ -119,9 +119,9 @@ def test_distinct_plane_keys_compile_separately_then_hold(plane_counters):
 
 @pytest.mark.parametrize("kw", [
     dict(scan_impl="fused_ref"),
-    dict(scan_impl="fused"),                       # Pallas (interpret on CPU)
+    dict(scan_impl="fused_interpret"),             # the Pallas kernel body
     dict(scan_impl="cascade_ref", budgets=(64, 32)),
-    dict(scan_impl="cascade", budgets=(64, 32)),
+    dict(scan_impl="cascade_interpret", budgets=(64, 32)),
 ], ids=["fused_ref", "fused", "cascade_ref", "cascade"])
 def test_fused_scan_paths_zero_implicit_transfers(kw):
     st, _, q = _build()

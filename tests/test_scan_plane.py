@@ -15,6 +15,7 @@ import subprocess
 import sys
 import textwrap
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -27,10 +28,11 @@ from repro.core.store import VectorStore
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 D, SEG_ROWS, N_SEG = 24, 128, 2
 
-# "pallas" (compiled) needs real TPU hardware; everything else runs on CPU,
-# with the Pallas kernel bodies executing in interpreter mode.
-BACKENDS = ["interpret", "fused", "fused_ref", "auto"]
-SELECT_BACKENDS = ["fused", "fused_ref"]
+# "pallas"/"fused" (compiled) need real TPU hardware; on CPU the Pallas
+# kernel bodies run in interpreter mode, asked for by name ("interpret",
+# "fused_interpret" — test ids drop the suffix, see conftest.py).
+BACKENDS = ["interpret", "fused_interpret", "fused_ref", "auto"]
+SELECT_BACKENDS = ["fused_interpret", "fused_ref"]
 
 
 def _cfg(s: int) -> HNTLConfig:
@@ -222,7 +224,8 @@ def test_plane_cache_shared_across_backend_aliases():
 
 def test_registry_names_and_errors():
     names = scan_plane_names()
-    for n in ("ref", "pallas", "interpret", "fused", "fused_ref", "auto"):
+    for n in ("ref", "pallas", "interpret", "fused", "fused_interpret",
+              "fused_ref", "auto"):
         assert n in names
     with pytest.raises(ValueError, match="unknown scan plane"):
         scanplane.get_scan_plane("nope")
@@ -232,8 +235,20 @@ def test_registry_names_and_errors():
     assert scanplane.get_scan_plane("ref").kind == scanplane.GATHER
 
 
+@pytest.mark.parametrize("backend", ["fused", "cascade", "pallas"])
+def test_compiled_kernel_planes_never_fall_back_on_cpu(backend):
+    """A compiled-kernel plane on a host without a TPU fails loudly: it is
+    never quietly interpreted or swapped for a reference plane (the
+    interpreter is asked for by name, e.g. "fused_interpret")."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("the compiled kernels run here")
+    st, x, q = _build_store(False, 0)
+    with pytest.raises(ValueError, match="interpret"):
+        st.search(q, topk=3, scan_impl=backend)
+
+
 @pytest.mark.parametrize("mode", ["A", "B"])
-@pytest.mark.parametrize("backend", ["ref", "fused_ref", "fused"])
+@pytest.mark.parametrize("backend", ["ref", "fused_ref", "fused_interpret"])
 def test_fully_pruned_pool_returns_all_minus_one(mode, backend):
     """Candidate-validity threshold regression (BIG/2 everywhere): a pool
     with every slot pruned by the in-situ predicate must come back as all

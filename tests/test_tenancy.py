@@ -29,7 +29,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 ROOT = os.path.join(os.path.dirname(__file__), "..")
 D = 16
 # every ScanPlane backend that runs on CPU (test_scan_plane.py contract)
-BACKENDS = [None, "interpret", "fused", "fused_ref", "auto"]
+BACKENDS = [None, "interpret", "fused_interpret", "fused_ref", "auto"]
 
 
 def run_sub(code: str):
