@@ -21,6 +21,7 @@ from jax.sharding import PartitionSpec as P
 
 from . import quantize, routing, scan, scanplane
 from .cascade import check_budgets
+from .spans import PROJECT, RERANK
 from .types import (BIG, HNTLIndex, RoutingPlane, SearchResult,
                     ShardedStackedSegments, StackedSegments)
 
@@ -58,6 +59,7 @@ def _gather_probed_panels(g, gids: jax.Array) -> dict:
                 sketch=g.sketch[gids] if g.sketch is not None else None)
 
 
+@jax.named_scope(PROJECT)
 def _project_quantized(index: HNTLIndex, q: jax.Array, gids: jax.Array,
                        envelope_frac: float, qeff: int):
     """Shared per-(query, probed grain) prep of both plane kinds: tangent
@@ -241,23 +243,12 @@ def search(index: HNTLIndex, q: jax.Array, *, nprobe: int, pool: int,
         width=min(max(pool, topk), nprobe * index.grains.cap),
         scan_impl=scan_impl, budgets=budgets, extra_mask=extra_mask)
 
-    if mode == "A":
-        neg_d, pos = jax.lax.top_k(-dists, topk)
-        ids_k = jnp.take_along_axis(ids, pos, axis=1)
-        d_k = -neg_d
-    else:
-        # Mode B: candidate pool C -> exact f32 L2 re-rank (cold tier).
-        assert index.raw is not None, "Mode B needs the raw (cold) tier"
-        neg_d, pos = jax.lax.top_k(-dists, pool)          # [Q, C]
-        cand_ids = jnp.take_along_axis(ids, pos, axis=1)  # [Q, C]
-        cand_ok = neg_d > -BIG / 2
-        cand = index.raw[jnp.maximum(cand_ids, 0)]        # [Q, C, d]
-        exact = jnp.sum((cand - q[:, None, :]) ** 2, axis=-1)
-        exact = jnp.where(cand_ok, exact, BIG)
-        neg_e, pos_e = jax.lax.top_k(-exact, topk)
-        ids_k = jnp.take_along_axis(cand_ids, pos_e, axis=1)
-        d_k = -neg_e
-    return SearchResult(ids=jnp.where(d_k < BIG / 2, ids_k, -1), dists=d_k)
+    # Mode B: candidate pool C -> exact f32 L2 re-rank over the raw tier
+    assert mode == "A" or index.raw is not None, \
+        "Mode B needs the raw (cold) tier"
+    return _candidate_epilogue(
+        dists, ids, q, index.raw, pool=pool, topk=topk, mode=mode,
+        translate=lambda i, d: jnp.where(d < BIG / 2, i, -1))
 
 
 # ---------------------------------------------------------------------------
@@ -313,10 +304,11 @@ def _translate_rows(stacked: StackedSegments, rows: jax.Array,
     return jnp.where(ok, gid, jnp.int32(-1))
 
 
+@jax.named_scope(RERANK)
 def _candidate_epilogue(dists, rows, q, raw, *, pool: int, topk: int,
                         mode: str, translate):
-    """Shared Mode A/B tail of the fused and sharded planes: candidate pool
-    -> (Mode B) exact f32 re-rank -> top-k -> id translation.
+    """Shared Mode A/B tail of every plane (single index, fused, sharded):
+    candidate pool -> (Mode B) exact f32 re-rank -> top-k -> id translation.
 
     ``translate``: fn(rows, dists) -> ids.  Both planes must keep using this
     one epilogue — the bit-for-bit parity contract between them depends on

@@ -21,6 +21,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .spans import ROUTE
 from .types import BIG, RoutingPlane
 
 
@@ -39,6 +40,7 @@ def _centroid_d2(plane: RoutingPlane, q: jax.Array,
     return jnp.where(ok, d2, BIG)
 
 
+@jax.named_scope(ROUTE)
 def route(plane: RoutingPlane, q: jax.Array, nprobe: int,
           grain_mask: Optional[jax.Array] = None):
     """Select the top-P closest grains per query.
@@ -78,6 +80,7 @@ def check_probe_args(adaptive: bool, probe_margin, min_probes=None) -> None:
             f"min_probes must be an int >= 1, got {min_probes!r}")
 
 
+@jax.named_scope(ROUTE)
 def adaptive_prefix(gids: jax.Array, gd2: jax.Array, *, margin: float,
                     min_probes: int = 1,
                     hub_mask: Optional[jax.Array] = None):
@@ -162,6 +165,7 @@ def rebuild_plane(centroids, sizes) -> RoutingPlane:
     return RoutingPlane(centroids=jnp.asarray(c), sizes=jnp.asarray(s))
 
 
+@jax.named_scope(ROUTE)
 def route_per_segment(plane: RoutingPlane, q: jax.Array, nprobe: int,
                       seg_shape: tuple,
                       grain_mask: Optional[jax.Array] = None):

@@ -51,6 +51,7 @@ import collections
 import contextlib
 import dataclasses
 import functools
+import itertools
 import math
 import os
 import tempfile
@@ -69,6 +70,7 @@ from . import maintenance
 from . import planner
 from . import residency
 from . import routing
+from . import spans
 from .types import (BIG, HNTLConfig, HNTLIndex, GrainStore, RoutingPlane,
                     SearchResult, ShardedStackedSegments, StackedSegments)
 
@@ -580,6 +582,8 @@ class VectorStore:
         # alongside the plane cache; a re-stack starts fresh counters.
         self._probe_traffic: "collections.OrderedDict" = \
             collections.OrderedDict()
+        self._calls = itertools.count()         # ``call`` of hntl.search
+        spans.install_gc_hook()
 
     # ------------------------------------------------------------- write path
     def _expiry_of(self, ttl, n: int) -> list:
@@ -1175,22 +1179,23 @@ class VectorStore:
         hit = self._cache_get(key)
         if hit is not None:
             return hit
-        stacked = stack_segments(segments)
-        gids = np.asarray(stacked.gid_of_row, np.int64)
-        entry = {
-            "plane": stacked,
-            "offsets": np.asarray(stacked.row_offset, np.int64),
-            "gids": gids,
-            "ids_host": np.asarray(stacked.index.grains.ids),
-            "row_gid": gids,
-            "row_seq": np.concatenate(
-                [s.global_seqs() for s in segments]),
-            "row_exp": _concat_expiry(segments),
-            "row_base": None,          # fused ids ARE global flat rows
-            "rules": None,             # single-device: plain device put
-            "live": (None, None),      # (epoch key, plane-with-live)
-        }
-        return self._cache_put(key, segments, entry)
+        with jax.profiler.TraceAnnotation(spans.PLANE_STACK):
+            stacked = stack_segments(segments)
+            gids = np.asarray(stacked.gid_of_row, np.int64)
+            entry = {
+                "plane": stacked,
+                "offsets": np.asarray(stacked.row_offset, np.int64),
+                "gids": gids,
+                "ids_host": np.asarray(stacked.index.grains.ids),
+                "row_gid": gids,
+                "row_seq": np.concatenate(
+                    [s.global_seqs() for s in segments]),
+                "row_exp": _concat_expiry(segments),
+                "row_base": None,          # fused ids ARE global flat rows
+                "rules": None,             # single-device: plain device put
+                "live": (None, None),      # (epoch key, plane-with-live)
+            }
+            return self._cache_put(key, segments, entry)
 
     # ------------------------------------------------------ tiered residency
     def _tiered_for(self, segments: tuple,
@@ -1210,38 +1215,40 @@ class VectorStore:
         hit = self._cache_get(key)
         if hit is not None:
             return hit
-        stacked = stack_segments(segments, device=False)
-        path = os.path.join(
-            self.cold_dir,
-            f"panels_{self._cold_tag}_{uuid.uuid4().hex[:8]}.soa")
-        tiered = residency.TieredPlane.from_stacked(stacked, path)
-        gids = np.asarray(stacked.gid_of_row, np.int64)
-        entry = {
-            "plane": tiered.routing_stub(),
-            "tiered": tiered,
-            "offsets": np.asarray(stacked.row_offset, np.int64),
-            "gids": gids,
-            "ids_host": tiered.panels["ids"],
-            "row_gid": gids,
-            "row_seq": np.concatenate(
-                [s.global_seqs() for s in segments]),
-            "row_exp": _concat_expiry(segments),
-            "row_base": None,
-            "rules": None,
-            "live": (None, None),
-            "live_host": (None, None),  # (epoch key, [G, cap] bitmap|None)
-            "keep": (None, None, None),  # (filter key, keep, grain_ok)
-            "raw_host": None,            # lazy warm-raw tier for Mode B
-            "searches": 0,
-            # Admission counters, SEPARATE from _probe_traffic: every tiered
-            # search feeds them, but _probe_traffic (hub set + probe_stats)
-            # only accumulates on adaptive searches — exactly like the
-            # all-warm plane, so hub masks and stats never diverge from it.
-            "r_wins": np.zeros(tiered.n_grains, np.int64),
-            "r_touches": np.zeros(tiered.n_grains, np.int64),
-        }
-        self._seed_hot(tiered)
-        return self._cache_put(key, segments, entry)
+        with jax.profiler.TraceAnnotation(spans.PLANE_STACK):
+            stacked = stack_segments(segments, device=False)
+            path = os.path.join(
+                self.cold_dir,
+                f"panels_{self._cold_tag}_{uuid.uuid4().hex[:8]}.soa")
+            tiered = residency.TieredPlane.from_stacked(stacked, path)
+            gids = np.asarray(stacked.gid_of_row, np.int64)
+            entry = {
+                "plane": tiered.routing_stub(),
+                "tiered": tiered,
+                "offsets": np.asarray(stacked.row_offset, np.int64),
+                "gids": gids,
+                "ids_host": tiered.panels["ids"],
+                "row_gid": gids,
+                "row_seq": np.concatenate(
+                    [s.global_seqs() for s in segments]),
+                "row_exp": _concat_expiry(segments),
+                "row_base": None,
+                "rules": None,
+                "live": (None, None),
+                "live_host": (None, None),  # (epoch key, [G, cap] bitmap|None)
+                "keep": (None, None, None),  # (filter key, keep, grain_ok)
+                "raw_host": None,            # lazy warm-raw tier for Mode B
+                "searches": 0,
+                # Admission counters, SEPARATE from _probe_traffic: every
+                # tiered search feeds them, but _probe_traffic (hub set +
+                # probe_stats) only accumulates on adaptive searches —
+                # exactly like the all-warm plane, so hub masks and stats
+                # never diverge from it.
+                "r_wins": np.zeros(tiered.n_grains, np.int64),
+                "r_touches": np.zeros(tiered.n_grains, np.int64),
+            }
+            self._seed_hot(tiered)
+            return self._cache_put(key, segments, entry)
 
     def _plane_entry_for(self, segments: tuple,
                          scan_impl: Optional[str] = None) -> dict:
@@ -1335,20 +1342,21 @@ class VectorStore:
         ck, cached = entry["live_host"]
         if ck == key:
             return key, cached
-        live_row = _live_rows(man.mut_gid, man.mut_seq,
-                              entry["row_gid"], entry["row_seq"])
-        if has_ttl:
-            alive_t = entry["row_exp"] > now
-            if not alive_t.all():
-                live_row = alive_t if live_row is None \
-                    else live_row & alive_t
-        bitmap = None
-        if live_row is not None:
-            ids = np.asarray(entry["ids_host"])
-            bitmap = (ids >= 0) & live_row[np.maximum(
-                ids.astype(np.int64), 0)]
-        entry["live_host"] = (key, bitmap)
-        return key, bitmap
+        with jax.profiler.TraceAnnotation(spans.PLANE_LIVE):
+            live_row = _live_rows(man.mut_gid, man.mut_seq,
+                                  entry["row_gid"], entry["row_seq"])
+            if has_ttl:
+                alive_t = entry["row_exp"] > now
+                if not alive_t.all():
+                    live_row = alive_t if live_row is None \
+                        else live_row & alive_t
+            bitmap = None
+            if live_row is not None:
+                ids = np.asarray(entry["ids_host"])
+                bitmap = (ids >= 0) & live_row[np.maximum(
+                    ids.astype(np.int64), 0)]
+            entry["live_host"] = (key, bitmap)
+            return key, bitmap
 
     def _tiered_keep(self, entry: dict, live_key, bitmap, tag_mask,
                      ts_range):
@@ -1424,7 +1432,8 @@ class VectorStore:
                                 ts_range, scan_impl, nprobe, pool, now,
                                 budgets=None, tenant_live=None,
                                 tenant_ix=None, adaptive=False,
-                                probe_margin=1.0, min_probes=1):
+                                probe_margin=1.0, min_probes=1,
+                                call_spans=spans.UNTRACED):
         """Paged fused search under a device byte budget.  Returns numpy
         (global_ids [Q, k], dists [Q, k]), bit-identical to the all-warm
         fused plane (modulo exact distance ties).
@@ -1493,6 +1502,7 @@ class VectorStore:
         # static plan) so results stay bit-identical to the static oracle.
         run_adaptive = adaptive and not math.isinf(probe_margin)
         traffic = None
+        call_spans.stage(spans.DISPATCH)
         if run_adaptive:
             traffic = self._traffic_for(segments, g_total)
             hub_host = self._hub_mask_host(traffic)
@@ -1537,6 +1547,7 @@ class VectorStore:
                 translate=False, probe_plan=(plan_h, na_d), **kw)
             pending.append((res_h, keep_b, None, q_n))
 
+        call_spans.stage(spans.READBACK)
         if run_adaptive:
             got = jax.device_get((gids_d, na_d, wins, touches))
             gids_h = np.asarray(got[0], np.int32)
@@ -1585,6 +1596,7 @@ class VectorStore:
                   if len(cold_gids) else [])
 
         def harvest(item):
+            call_spans.stage(spans.READBACK)
             res, keep_b, qsel, n_act = item
             r = np.asarray(jax.device_get(res.ids), np.int64)
             dm = np.asarray(jax.device_get(res.dists), np.float32)
@@ -1599,6 +1611,7 @@ class VectorStore:
         for ch in chunks:
             if len(pending) >= 2:     # block on k-1, keep k in flight
                 harvest(pending.pop(0))
+            call_spans.stage(spans.DISPATCH)
             plane_c, member = tiered.chunk_plane(ch, bitmap, live_key)
             plan = residency.compact_probes(gids_h, na_h, member, len(ch))
             if plan is None:
@@ -1633,15 +1646,18 @@ class VectorStore:
             ids = np.where(ok_p, gids_host[np.maximum(r_p, 0)], -1)
             return ids.astype(np.int64), d_p.astype(np.float32)
         if not warm:
+            call_spans.stage(spans.COLD_RERANK)
             return self._cold_rerank(q, segments, offsets, gids_host,
                                      r_p, ok_p, topk_eff)
         # warm Mode B: exact re-rank of the merged pool on device, with
         # the raw rows gathered host-side (the stacked raw tier is never
         # device-resident on the tiered plane)
+        call_spans.stage(spans.DISPATCH)
         raw = self._tiered_raw_host(entry, segments)
         rows_c = np.maximum(r_p, 0)
         pos, d = _rerank_pool(jax.device_put(raw[rows_c]), qj,
                               jax.device_put(ok_p), topk=topk_eff)
+        call_spans.stage(spans.READBACK)
         pos_h = np.asarray(jax.device_get(pos))
         d_h = np.asarray(jax.device_get(d), np.float32)
         ids_pool = np.where(ok_p, gids_host[rows_c], -1)
@@ -1672,38 +1688,39 @@ class VectorStore:
         hit = self._cache_get(key)
         if hit is not None:
             return hit
-        n_shards = mesh.shape[grain_axis]
-        plane, perm = shard_segments(segments, n_shards)
-        ids_host = np.asarray(plane.index.grains.ids)
-        rules = shd.search_plane_rules(mesh, grain_axis=grain_axis)
-        reuse = self._reusable_row_leaves(segments, mesh, grain_axis,
-                                          _plane_key(scan_impl), perm)
-        plane = shd.shard_search_plane(plane, rules, reuse=reuse)
-        offsets = np.zeros(len(segments) + 1, np.int64)
-        np.cumsum([s.n for s in segments], out=offsets[1:])
-        gids = np.concatenate([s.global_ids() for s in segments])
-        seqs = np.concatenate([s.global_seqs() for s in segments])
-        exp = _concat_expiry(segments)
-        keep = np.maximum(perm, 0)
-        g_total = ids_host.shape[0]
-        rows_local = len(perm) // n_shards
-        entry = {
-            "plane": plane,
-            "perm": perm,
-            "offsets": offsets,
-            "gids": gids,
-            "ids_host": ids_host,
-            "row_gid": np.where(perm >= 0, gids[keep], -1),
-            "row_seq": np.where(perm >= 0, seqs[keep], -1),
-            "row_exp": (np.where(perm >= 0, exp[keep], np.inf)
-                        if exp is not None else None),
-            # shard-local panel ids -> permuted global rows: + shard offset
-            "row_base": (np.arange(g_total) // (g_total // n_shards)
-                         * rows_local),
-            "rules": rules,
-            "live": (None, None),
-        }
-        return self._cache_put(key, segments, entry)
+        with jax.profiler.TraceAnnotation(spans.PLANE_STACK):
+            n_shards = mesh.shape[grain_axis]
+            plane, perm = shard_segments(segments, n_shards)
+            ids_host = np.asarray(plane.index.grains.ids)
+            rules = shd.search_plane_rules(mesh, grain_axis=grain_axis)
+            reuse = self._reusable_row_leaves(segments, mesh, grain_axis,
+                                              _plane_key(scan_impl), perm)
+            plane = shd.shard_search_plane(plane, rules, reuse=reuse)
+            offsets = np.zeros(len(segments) + 1, np.int64)
+            np.cumsum([s.n for s in segments], out=offsets[1:])
+            gids = np.concatenate([s.global_ids() for s in segments])
+            seqs = np.concatenate([s.global_seqs() for s in segments])
+            exp = _concat_expiry(segments)
+            keep = np.maximum(perm, 0)
+            g_total = ids_host.shape[0]
+            rows_local = len(perm) // n_shards
+            entry = {
+                "plane": plane,
+                "perm": perm,
+                "offsets": offsets,
+                "gids": gids,
+                "ids_host": ids_host,
+                "row_gid": np.where(perm >= 0, gids[keep], -1),
+                "row_seq": np.where(perm >= 0, seqs[keep], -1),
+                "row_exp": (np.where(perm >= 0, exp[keep], np.inf)
+                            if exp is not None else None),
+                # shard-local panel ids -> permuted global rows: + shard offset
+                "row_base": (np.arange(g_total) // (g_total // n_shards)
+                             * rows_local),
+                "rules": rules,
+                "live": (None, None),
+            }
+            return self._cache_put(key, segments, entry)
 
     def _reusable_row_leaves(self, segments: tuple, mesh, grain_axis: str,
                              plane_key: str, perm: np.ndarray):
@@ -1744,28 +1761,30 @@ class VectorStore:
         ck, cached = entry["live"]
         if ck == key:
             return cached
-        live_row = _live_rows(man.mut_gid, man.mut_seq,
-                              entry["row_gid"], entry["row_seq"])
-        if has_ttl:
-            alive_t = entry["row_exp"] > now
-            if not alive_t.all():
-                live_row = alive_t if live_row is None \
-                    else live_row & alive_t
-        plane = entry["plane"]
-        if live_row is not None:
-            ids = entry["ids_host"]
-            rows = ids.astype(np.int64)
-            if entry["row_base"] is not None:
-                rows = rows + entry["row_base"][:, None]
-            bitmap = (ids >= 0) & live_row[np.maximum(rows, 0)]
-            if entry["rules"] is not None:
-                from ..distributed import sharding as shd
-                leaf = shd.shard_plane_field(bitmap, entry["rules"], "live")
-            else:
-                leaf = jnp.asarray(bitmap)
-            plane = dataclasses.replace(plane, live=leaf)
-        entry["live"] = (key, plane)
-        return plane
+        with jax.profiler.TraceAnnotation(spans.PLANE_LIVE):
+            live_row = _live_rows(man.mut_gid, man.mut_seq,
+                                  entry["row_gid"], entry["row_seq"])
+            if has_ttl:
+                alive_t = entry["row_exp"] > now
+                if not alive_t.all():
+                    live_row = alive_t if live_row is None \
+                        else live_row & alive_t
+            plane = entry["plane"]
+            if live_row is not None:
+                ids = entry["ids_host"]
+                rows = ids.astype(np.int64)
+                if entry["row_base"] is not None:
+                    rows = rows + entry["row_base"][:, None]
+                bitmap = (ids >= 0) & live_row[np.maximum(rows, 0)]
+                if entry["rules"] is not None:
+                    from ..distributed import sharding as shd
+                    leaf = shd.shard_plane_field(bitmap, entry["rules"],
+                                                 "live")
+                else:
+                    leaf = jnp.asarray(bitmap)
+                plane = dataclasses.replace(plane, live=leaf)
+            entry["live"] = (key, plane)
+            return plane
 
     def search(self, q: np.ndarray, *, topk: int = 10, mode: str = "B",
                tag_mask: Optional[int] = None,
@@ -1827,73 +1846,80 @@ class VectorStore:
         now: TTL clock override (default: the store clock).  Records whose
           TTL deadline passed are masked exactly like tombstones.
         """
-        man = manifest or self.snapshot()
-        now = self._clock() if now is None else now
         q = np.asarray(q, np.float32)
         if q.ndim == 1:
             q = q[None]
-        if budgets is not None:
-            from .cascade import check_budgets
-            check_budgets(budgets, topk)
-            if not fused:
-                raise ValueError(
-                    "budgets= needs the fused search plane; the legacy "
-                    "looped path has no staged candidate stage")
-        routing.check_probe_args(adaptive, probe_margin, min_probes)
-        if adaptive:
-            if not fused:
-                raise ValueError(
-                    "adaptive=True needs the fused search plane; the "
-                    "legacy looped path has no ragged-probe stage")
-            if route_mode != "global":
-                raise ValueError(
-                    "adaptive=True needs route_mode='global' (the "
-                    "stopping rule compares one fused routing pass)")
-        margin = (self.cfg.probe_margin if probe_margin is None
-                  else float(probe_margin))
-        minp = self.cfg.min_probes if min_probes is None else int(min_probes)
-        if not fused:
-            if mesh is not None:
-                raise ValueError("mesh= requires the fused search plane")
-            if self.device_budget is not None:
-                raise ValueError(
-                    "device_budget= (tiered residency) pages through the "
-                    "fused stacked plane; fused=False has no paged path")
-            return self._search_looped(q, man, topk=topk, mode=mode,
-                                       tag_mask=tag_mask, ts_range=ts_range,
-                                       scan_impl=scan_impl, now=now)
-        all_ids, all_d = [], []
-        if man.segments:
-            if mesh is not None:
+        with spans.SearchSpans(q.shape[0], next(self._calls)) as call_spans:
+            man = manifest or self.snapshot()
+            now = self._clock() if now is None else now
+            if budgets is not None:
+                from .cascade import check_budgets
+                check_budgets(budgets, topk)
+                if not fused:
+                    raise ValueError(
+                        "budgets= needs the fused search plane; the legacy "
+                        "looped path has no staged candidate stage")
+            routing.check_probe_args(adaptive, probe_margin, min_probes)
+            if adaptive:
+                if not fused:
+                    raise ValueError(
+                        "adaptive=True needs the fused search plane; the "
+                        "legacy looped path has no ragged-probe stage")
                 if route_mode != "global":
                     raise ValueError(
-                        "the sharded plane routes per shard; route_mode "
-                        "overrides only apply to the single-device plane")
+                        "adaptive=True needs route_mode='global' (the "
+                        "stopping rule compares one fused routing pass)")
+            margin = (self.cfg.probe_margin if probe_margin is None
+                      else float(probe_margin))
+            minp = (self.cfg.min_probes if min_probes is None
+                    else int(min_probes))
+            if not fused:
+                if mesh is not None:
+                    raise ValueError(
+                        "mesh= requires the fused search plane")
                 if self.device_budget is not None:
                     raise ValueError(
-                        "device_budget= (tiered residency) is single-device"
-                        "; the sharded plane (mesh=) keeps every shard "
-                        "resident — drop one of the two")
-                ids_s, d_s = self._search_segments_sharded(
+                        "device_budget= (tiered residency) pages through "
+                        "the fused stacked plane; fused=False has no paged "
+                        "path")
+                return self._search_looped(
                     q, man, topk=topk, mode=mode, tag_mask=tag_mask,
-                    ts_range=ts_range, scan_impl=scan_impl,
-                    budgets=budgets, nprobe=nprobe, pool=pool, mesh=mesh,
-                    grain_axis=grain_axis,
-                    shard_queries=shard_queries, now=now,
-                    adaptive=adaptive, probe_margin=margin,
-                    min_probes=minp)
-            else:
-                ids_s, d_s = self._search_segments_fused(
-                    q, man, topk=topk, mode=mode, tag_mask=tag_mask,
-                    ts_range=ts_range, scan_impl=scan_impl,
-                    budgets=budgets, nprobe=nprobe, pool=pool,
-                    route_mode=route_mode, now=now,
-                    adaptive=adaptive, probe_margin=margin,
-                    min_probes=minp)
-            all_ids.append(ids_s)
-            all_d.append(d_s)
-        return self._merge_with_memtable(q, man, all_ids, all_d, topk,
-                                         tag_mask, ts_range, now)
+                    ts_range=ts_range, scan_impl=scan_impl, now=now,
+                    call_spans=call_spans)
+            all_ids, all_d = [], []
+            if man.segments:
+                if mesh is not None:
+                    if route_mode != "global":
+                        raise ValueError(
+                            "the sharded plane routes per shard; route_mode "
+                            "overrides only apply to the single-device "
+                            "plane")
+                    if self.device_budget is not None:
+                        raise ValueError(
+                            "device_budget= (tiered residency) is "
+                            "single-device; the sharded plane (mesh=) keeps "
+                            "every shard resident — drop one of the two")
+                    ids_s, d_s = self._search_segments_sharded(
+                        q, man, topk=topk, mode=mode, tag_mask=tag_mask,
+                        ts_range=ts_range, scan_impl=scan_impl,
+                        budgets=budgets, nprobe=nprobe, pool=pool,
+                        mesh=mesh, grain_axis=grain_axis,
+                        shard_queries=shard_queries, now=now,
+                        adaptive=adaptive, probe_margin=margin,
+                        min_probes=minp, call_spans=call_spans)
+                else:
+                    ids_s, d_s = self._search_segments_fused(
+                        q, man, topk=topk, mode=mode, tag_mask=tag_mask,
+                        ts_range=ts_range, scan_impl=scan_impl,
+                        budgets=budgets, nprobe=nprobe, pool=pool,
+                        route_mode=route_mode, now=now,
+                        adaptive=adaptive, probe_margin=margin,
+                        min_probes=minp, call_spans=call_spans)
+                all_ids.append(ids_s)
+                all_d.append(d_s)
+            call_spans.stage(spans.FINALIZE)
+            return self._merge_with_memtable(q, man, all_ids, all_d, topk,
+                                             tag_mask, ts_range, now)
 
     def _merge_with_memtable(self, q, man: Manifest, all_ids, all_d, topk,
                              tag_mask, ts_range, now) -> SearchResult:
@@ -1935,7 +1961,7 @@ class VectorStore:
                                route_mode, now, budgets=None,
                                tenant_live=None, tenant_ix=None,
                                adaptive=False, probe_margin=1.0,
-                               min_probes=1):
+                               min_probes=1, call_spans=spans.UNTRACED):
         """One jitted search over the stacked plane.  Returns numpy
         (global_ids [Q, k], dists [Q, k]).
 
@@ -1943,7 +1969,8 @@ class VectorStore:
         tenant visibility for the coalesced serving plane — the manifest is
         then the registry's *union* of segments and per-tenant
         liveness/membership arrives through these masks instead of the
-        manifest's own mutation table."""
+        manifest's own mutation table.  ``call_spans``: the calling
+        search's spans (``core.spans``), whose stages this path marks."""
         if self.device_budget is not None:
             # Tiered residency: same search, paged data plane.  Routing
             # still sees every grain (the stub is panel-free, not lossy);
@@ -1958,7 +1985,7 @@ class VectorStore:
                 pool=pool, now=now, budgets=budgets,
                 tenant_live=tenant_live, tenant_ix=tenant_ix,
                 adaptive=adaptive, probe_margin=probe_margin,
-                min_probes=min_probes)
+                min_probes=min_probes, call_spans=call_spans)
         segments = man.segments
         entry = self._stacked_for(segments, scan_impl)
         stacked = self._live_plane(entry, man, now)
@@ -1992,7 +2019,8 @@ class VectorStore:
                 budgets=budgets, probe_margin=probe_margin,
                 min_probes=min_probes,
                 tenant_ix_host=(np.asarray(tenant_ix, np.int32)
-                                if tenant_ix is not None else None))
+                                if tenant_ix is not None else None),
+                call_spans=call_spans)
 
         if mode == "B" and stacked.index.raw is None:
             # Cold tier: one jitted approximate scan over the whole stack,
@@ -2002,24 +2030,30 @@ class VectorStore:
             # width the host re-rank reads shrinks with it.
             pe = (pool_eff if budgets is None
                   else min(pool_eff, int(budgets[1])))
+            call_spans.stage(spans.DISPATCH)
             res = planner.search_stacked(stacked, qj, pool=pool_eff,
                                          topk=pe, mode="A",
                                          translate=False, **kw)
+            call_spans.stage(spans.READBACK)
             rows = jax.device_get(res.ids)
             ok = (rows >= 0) & (jax.device_get(res.dists) < BIG / 2)
+            call_spans.stage(spans.COLD_RERANK)
             return self._cold_rerank(q, segments, offsets, gids_host,
                                      rows, ok, topk_eff)
 
+        call_spans.stage(spans.DISPATCH)
         res = planner.search_stacked(stacked, qj, pool=pool_eff,
                                      topk=topk_eff, mode=mode, **kw)
         # Explicit D2H: the one sanctioned device->host hop of the warm
         # tier (the final top-k), visible to the transfer guard as such.
+        call_spans.stage(spans.READBACK)
         return (np.asarray(jax.device_get(res.ids), np.int64),
                 np.asarray(jax.device_get(res.dists), np.float32))
 
     def _adaptive_fused(self, q, qj, segments, stacked, entry, kw, *, mode,
                         pool_eff, topk_eff, probe, budgets, probe_margin,
-                        min_probes, tenant_ix_host=None):
+                        min_probes, tenant_ix_host=None,
+                        call_spans=spans.UNTRACED):
         """Two-phase bucketed adaptive dispatch over the fused plane.
 
         Phase 1 (``planner.probe_plan``): ONE jitted routing pass applies
@@ -2043,10 +2077,12 @@ class VectorStore:
         for k in ("tenant_live", "tenant_ix"):
             if k in kw:
                 pkw[k] = kw[k]
+        call_spans.stage(spans.DISPATCH)
         gids_d, na_d, wins, touches = planner.probe_plan(
             stacked, qj, nprobe=probe, probe_margin=probe_margin,
             min_probes=min_probes, hub_mask=hub, **pkw)
         # Explicit D2H of the plan: the host bucketing phase is the point.
+        call_spans.stage(spans.READBACK)
         gids_h = np.asarray(jax.device_get(gids_d), np.int32)
         na_h = np.asarray(jax.device_get(na_d), np.int32)
         traffic["wins"] += np.asarray(jax.device_get(wins), np.int64)
@@ -2071,6 +2107,7 @@ class VectorStore:
             wq = np.where(wq < na_h, wq * 2, wq)
         wq = np.minimum(wq, probe)
         for w in sorted(int(v) for v in np.unique(wq)):
+            call_spans.stage(spans.DISPATCH)
             sel = np.nonzero(wq == w)[0]
             # clamp the pool to what w grains can hold: a narrow bucket
             # must not ask top-k for more slots than it scans
@@ -2087,6 +2124,7 @@ class VectorStore:
                 res = planner.search_stacked(
                     stacked, qb, pool=pool_b, topk=pe_b, mode="A",
                     translate=False, probe_plan=plan, **bkw)
+                call_spans.stage(spans.READBACK)
                 out_ids[sel[:, None], np.arange(pe_b)[None, :]] = \
                     jax.device_get(res.ids)
                 out_d[sel[:, None], np.arange(pe_b)[None, :]] = \
@@ -2095,12 +2133,14 @@ class VectorStore:
                 res = planner.search_stacked(
                     stacked, qb, pool=pool_b, topk=topk_b, mode=mode,
                     probe_plan=plan, **bkw)
+                call_spans.stage(spans.READBACK)
                 out_ids[sel[:, None], np.arange(topk_b)[None, :]] = \
                     np.asarray(jax.device_get(res.ids), np.int64)
                 out_d[sel[:, None], np.arange(topk_b)[None, :]] = \
                     jax.device_get(res.dists)
         if cold:
             ok = (out_ids >= 0) & (out_d < _BIG / 2)
+            call_spans.stage(spans.COLD_RERANK)
             return self._cold_rerank(q, segments, entry["offsets"],
                                      entry["gids"], out_ids, ok, topk_eff)
         return out_ids, out_d
@@ -2160,7 +2200,8 @@ class VectorStore:
                                  grain_axis, shard_queries, now,
                                  budgets=None, tenant_live=None,
                                  tenant_ix=None, adaptive=False,
-                                 probe_margin=1.0, min_probes=1):
+                                 probe_margin=1.0, min_probes=1,
+                                 call_spans=spans.UNTRACED):
         """Distributed fused search: shard-local route/scan/pool/re-rank and
         one all-gather merge collective.  Returns numpy (global_ids, dists).
 
@@ -2219,20 +2260,25 @@ class VectorStore:
             # Stage budgets cap each shard's useful pool at b2.
             pe = (pool_eff if budgets is None
                   else min(pool_eff, int(budgets[1])))
+            call_spans.stage(spans.DISPATCH)
             res = planner.search_stacked_sharded(
                 plane, qj, pool=pe, topk=n_shards * pe,
                 mode="A", translate=False, **kw)
+            call_spans.stage(spans.READBACK)
             rows_perm = jax.device_get(res.ids)
             ok = (rows_perm >= 0) & (jax.device_get(res.dists) < BIG / 2)
             rows = np.where(ok, perm[np.maximum(rows_perm, 0)], -1)
             ok &= rows >= 0
+            call_spans.stage(spans.COLD_RERANK)
             return self._cold_rerank(q, segments, offsets, gids_host,
                                      rows, ok, min(topk, rows.shape[1]))
 
+        call_spans.stage(spans.DISPATCH)
         res = planner.search_stacked_sharded(plane, qj, pool=pool_eff,
                                              topk=topk, mode=mode, **kw)
         # Explicit D2H: the one sanctioned device->host hop of the warm
         # tier (the final top-k), visible to the transfer guard as such.
+        call_spans.stage(spans.READBACK)
         return (np.asarray(jax.device_get(res.ids), np.int64),
                 np.asarray(jax.device_get(res.dists), np.float32))
 
@@ -2292,7 +2338,8 @@ class VectorStore:
         return (ids >= 0) & lv[np.maximum(ids, 0)]
 
     def _search_looped(self, q, man: Manifest, *, topk, mode, tag_mask,
-                       ts_range, scan_impl, now) -> SearchResult:
+                       ts_range, scan_impl, now,
+                       call_spans=spans.UNTRACED) -> SearchResult:
         """Per-segment Python-loop search (pre-fusion data plane).
 
         Kept as the parity oracle for `search` and the baseline for
@@ -2301,6 +2348,7 @@ class VectorStore:
         """
         all_ids, all_d = [], []
         for seg in man.segments:
+            call_spans.stage(spans.DISPATCH)
             extra = None
             g = seg.index.grains
             live = self._seg_live_mask(man, seg, now)
@@ -2319,11 +2367,13 @@ class VectorStore:
                 res = index_mod.search(seg.index, q, self.cfg, topk=max(
                     topk, self.cfg.pool), mode="A", scan_impl=scan_impl,
                     extra_mask=extra)
+                call_spans.stage(spans.READBACK)
                 raw = seg.raw_vectors()
                 cand = np.asarray(res.ids)
                 # candidates pruned in-scan (validity / mixed-recall mask) come
                 # back with approx dist = BIG; keep them pruned through re-rank
                 cand_ok = (cand >= 0) & (np.asarray(res.dists) < BIG / 2)
+                call_spans.stage(spans.COLD_RERANK)
                 exact = np.sum(
                     (raw[np.maximum(cand, 0)] - q[:, None, :]) ** 2, axis=-1)
                 exact = np.where(cand_ok, exact, _BIG)
@@ -2334,8 +2384,10 @@ class VectorStore:
                 res = index_mod.search(seg.index, q, self.cfg, topk=topk,
                                        mode=mode, scan_impl=scan_impl,
                                        extra_mask=extra)
+                call_spans.stage(spans.READBACK)
                 ids, d = np.asarray(res.ids), np.asarray(res.dists)
             all_ids.append(seg.map_local(ids))
             all_d.append(d)
+        call_spans.stage(spans.FINALIZE)
         return self._merge_with_memtable(q, man, all_ids, all_d, topk,
                                          tag_mask, ts_range, now)

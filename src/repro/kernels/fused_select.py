@@ -409,5 +409,6 @@ def _select_call(*, gids, zq, rq, keep, sq, tenant_ix, n_active, coords,
             jax.ShapeDtypeStruct((q_n, 1, w_pad), jnp.int32),
         ],
         interpret=interpret,
+        name="fused_scan_select",
     )(gids, mgids, na, *args)
     return out_d[:, 0, :width], out_r[:, 0, :width]

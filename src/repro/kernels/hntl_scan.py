@@ -147,6 +147,7 @@ def hntl_scan(zq, rq, coords, res, valid, scale, res_scale, *,
             (None, blk_q, BLK_C), lambda g, i, j: (g, i, j)),
         out_shape=jax.ShapeDtypeStruct((p, qp, capp), jnp.float32),
         interpret=interpret,
+        name="hntl_scan",
     )(
         zq,
         rq[..., None],
@@ -219,6 +220,7 @@ def hntl_scan_single(zq, rq, coords, res, valid, scale, res_scale, *,
         out_specs=pl.BlockSpec((None, 1, BLK_C), lambda g, j: (g, 0, j)),
         out_shape=jax.ShapeDtypeStruct((p, 1, capp), jnp.float32),
         interpret=interpret,
+        name="hntl_scan_single",
     )(
         zq[:, :, None],
         rq[:, None, None],

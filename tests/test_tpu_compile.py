@@ -11,8 +11,11 @@ not interpreted.
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -131,3 +134,25 @@ def test_hntl_scan_batched_compiles(one_chip, q):
     products on the MXU."""
     _compile(hntl_scan, _scan_shapes(P, q, True), one_chip)
 
+
+
+def test_search_program_names_the_fused_kernel(one_chip):
+    """The whole search program, compiled for the chip: the scan→select
+    kernel is the HLO instruction ``fused_scan_select`` (what the
+    benchmark's trace readers match), beside the program's named scopes."""
+    from repro.core import HNTLConfig, planner, spans
+    from repro.core.store import VectorStore
+    cfg = HNTLConfig(d=K, k=8, s=S, n_grains=4, nprobe=4, pool=WIDTH)
+    st = VectorStore(cfg, seal_threshold=512)
+    st.add(np.random.default_rng(0).standard_normal(
+        (1024, K)).astype(np.float32))
+    plane = st._stacked_for(st.snapshot().segments)["plane"]
+    shaped = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (plane, jnp.zeros((1, K), jnp.float32)))
+    text = planner.search_stacked.lower(
+        *shaped, nprobe=4, pool=WIDTH, topk=10,
+        scan_impl="fused").compile().as_text()
+    assert re.search(r"%fused_scan_select(\.\d+)? = .*custom-call", text)
+    for scope in (spans.ROUTE, spans.PROJECT, spans.RERANK):
+        assert f"/{scope}/" in text, scope
