@@ -8,7 +8,7 @@ scan/select boundary):
 
 - the probed grain ids arrive as a **scalar-prefetch** argument, so every
   block ``index_map`` computes its HBM offset from ``gids[q, p]`` and the
-  pipeline streams only the probed ``[k, BLK_C]`` panels straight out of the
+  pipeline streams only the probed ``[k, T]`` panels straight out of the
   stacked index — the [Q, P, k, cap] gather copy never exists;
 - a per-query running candidate set (dists + rows + arrival order) lives
   in VMEM scratch and is carried across the sequential (probe, cap-tile)
@@ -24,15 +24,23 @@ scan/select boundary):
   kernel pass in ``ops.scan_batched``), the envelope kill, and the combined
   validity/liveness/tag/ts mask.
 
-Grid: (Q, P, cap-tiles); the leading query axis is embarrassingly parallel
-(each query owns its scratch carry — a megacore split on q is safe), the
-trailing two axes are sequential reductions into the carry.
+Grid: (Q, P, capp/T), where capp is the capacity padded to whole 128-lane
+columns and T = ``select_tile(capp, k, s)`` is the widest run of columns
+whose double-buffered blocks fit a fixed VMEM budget: the whole grain at
+the paper's shapes, so one grid step scores one probed grain.  A step's
+fixed cost (index maps, DMA issue and wait, the merge's loop test) is paid
+once per grain, not once per 128 slots.  The body computes the step's
+distances 128 lanes at a time into a [T/128, 128] scratch (bounded live
+vregs) and merges them into the carry in one pass.  The leading query axis
+is embarrassingly parallel (each query owns its scratch carry — a megacore
+split on q is safe), the trailing two axes are sequential reductions into
+the carry.
 
 Multi-tenant serving rides the same machinery with a SECOND scalar-prefetch
 stream: the mask argument generalizes to a flattened [T*G, cap] per-tenant
 visibility table and ``mgids[q, p] = tenant_ix[q] * G + gids[q, p]`` drives
 its block index map, so every (query, probe) cell streams exactly its own
-tenant's [1, BLK_C] mask tile.  No [Q, P, cap] per-query mask is ever
+tenant's [1, T] mask tile.  No [Q, P, cap] per-query mask is ever
 materialized — tenant state in HBM is O(T·G·cap), shared across queries —
 and the no-tenant path simply passes ``mgids = gids`` with the usual
 [G, cap] mask (same kernel, no extra cost).
@@ -76,7 +84,11 @@ from jax.experimental.pallas import tpu as pltpu
 # equal to types.BIG — asserted in tests/test_kernels.py.
 NEG_BIG = 3.0e38  # hntlint: ok H004
 
-BLK_C = 128   # cap-tile columns (lane dimension)
+BLK_C = 128   # lane column: caps pad to whole columns, distances are
+              # computed one column at a time
+# VMEM for one grid step's double-buffered grain blocks and its distance
+# scratch: half of v5e's 16 MiB default scoped VMEM (select_tile).
+TILE_VMEM_BYTES = 8 << 20
 # Entries per flat scalar-prefetch stream (gids, mask gids: [Q*P] i32 each)
 # in one kernel call: two 256 KiB streams use half of a v5e core's 1 MiB
 # SMEM.  Larger batches are split along the query axis.
@@ -84,54 +96,73 @@ PREFETCH_ENTRIES = 1 << 16
 _I32_MAX = 2 ** 31 - 1
 
 
-def _sq_dist_int(z_ref, panel_ref):
-    """Exact int32 ``sum_k (z_k - panel_k)^2`` per lane: z_ref [k, 1] i32
-    broadcast over the lanes of panel_ref [k, BLK_C] -> [1, BLK_C].  A VPU
+def select_tile(capp: int, k: int, s: int) -> int:
+    """Lane width T of one grid step over a padded capacity ``capp`` (a
+    multiple of BLK_C) with ``k`` coordinate and ``s`` sketch rows (0 where
+    the term is absent): the largest multiple of BLK_C that divides
+    ``capp`` and whose blocks fit TILE_VMEM_BYTES, the whole grain when it
+    fits.  Per lane, double-buffered: coords [k, T] i16 (16 sublanes a
+    tile), sketch [s, T] i8 (32), res/mask/rows [1, T] i32 (8 each); plus
+    the two [T/128, 128] scratch rows."""
+    per_lane = 2 * (2 * _round_up(k, 16) + _round_up(s, 32) + 3 * 8 * 4) + 8
+    cols = capp // BLK_C
+    fits = [t for t in range(1, cols + 1)
+            if cols % t == 0 and t * BLK_C * per_lane <= TILE_VMEM_BYTES]
+    return BLK_C * max(fits, default=1)
+
+
+def _sq_dist_int(z, panel):
+    """Exact int32 ``sum_k (z_k - panel_k)^2`` per lane: z [k, 1] i32
+    broadcast over the lanes of panel [k, BLK_C] -> [1, BLK_C].  A VPU
     reduction over the sublane (k) axis: Mosaic has no int32 matmul."""
-    diff = z_ref[...] - panel_ref[...].astype(jnp.int32)
+    diff = z - panel.astype(jnp.int32)
     return jnp.sum(diff * diff, axis=0, keepdims=True)
 
 
 def _merge_tile(tile_d, tile_r, arrival0, best_d, best_r, best_a,
                 width: int):
-    """Two-stage select, stage 2: fold one tile's [1, BLK_C] candidates into
-    the running best-``width`` set held in lanes < width of the carry.
+    """Two-stage select, stage 2: fold one step's candidates, [T/128, 128]
+    with slot ``row * 128 + lane``, into the running best-``width`` set held
+    in lanes < width of the carry.
 
-    While the tile's best candidate (smallest distance, lowest lane on a
+    While the tile's best candidate (smallest distance, lowest slot on a
     tie) beats the set's worst member (largest distance, latest arrival on
     a tie), it replaces that member.  A tile candidate that only ties the
     worst member loses: the member arrived earlier.  So the set is always
     the first ``width`` candidates seen under the order (distance, arrival),
     which is ``lax.top_k``'s order on the concatenated stream.
     """
-    lane_t = jax.lax.broadcasted_iota(jnp.int32, tile_d.shape, 1)
+    lane_t = (jax.lax.broadcasted_iota(jnp.int32, tile_d.shape, 0) * BLK_C
+              + jax.lax.broadcasted_iota(jnp.int32, tile_d.shape, 1))
     lane_w = jax.lax.broadcasted_iota(jnp.int32, best_d.shape, 1)
     in_set = lane_w < width
 
     def worst(cd):
         return jnp.max(jnp.where(in_set, cd, -jnp.inf))
 
+    # The loop state carries the tile's best distance m and the set's worst
+    # v, so the loop test itself reduces nothing.
     def beats_worst(state):
-        td, cd, _, _ = state
-        return jnp.min(td) < worst(cd)
+        *_, m, v = state
+        return m < v
 
     def replace_worst(state):
-        td, cd, cr, ca = state
-        m = jnp.min(td)
-        i = jnp.min(jnp.where(td == m, lane_t, BLK_C))
+        td, cd, cr, ca, m, v = state
+        i = jnp.min(jnp.where(td == m, lane_t, tile_d.size))
         r = jnp.sum(jnp.where(lane_t == i, tile_r, 0))
-        v = worst(cd)
         at_v = jnp.logical_and(in_set, cd == v)
         a = jnp.max(jnp.where(at_v, ca, -1))
         e = jnp.max(jnp.where(jnp.logical_and(at_v, ca == a), lane_w, -1))
         hit = lane_w == e
-        return (jnp.where(lane_t == i, jnp.inf, td),
-                jnp.where(hit, m, cd), jnp.where(hit, r, cr),
-                jnp.where(hit, arrival0 + i, ca))
+        td = jnp.where(lane_t == i, jnp.inf, td)
+        cd = jnp.where(hit, m, cd)
+        return (td, cd, jnp.where(hit, r, cr),
+                jnp.where(hit, arrival0 + i, ca), jnp.min(td), worst(cd))
 
-    _, cd, cr, ca = jax.lax.while_loop(
+    cd = best_d[...]
+    _, cd, cr, ca, _, _ = jax.lax.while_loop(
         beats_worst, replace_worst,
-        (tile_d, best_d[...], best_r[...], best_a[...]))
+        (tile_d, cd, best_r[...], best_a[...], jnp.min(tile_d), worst(cd)))
     best_d[...] = cd
     best_r[...] = cr
     best_a[...] = ca
@@ -186,7 +217,8 @@ def _make_select_kernel(has_coords: bool, has_sketch: bool, width: int):
         scale_ref = take(has_coords)
         res_scale_ref = take()
         sketch_ref, sk_scale_ref = take(has_sketch), take(has_sketch)
-        out_d_ref, out_r_ref, best_d, best_r, best_a = refs
+        out_d_ref, out_r_ref, best_d, best_r, best_a, tile_d, tile_r = refs
+        n_cols = tile_d.shape[0]                 # 128-lane columns a step
         q_i, p_i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
         n_tiles = pl.num_programs(2)
 
@@ -202,24 +234,33 @@ def _make_select_kernel(has_coords: bool, has_sketch: bool, width: int):
         # skips cost no HBM traffic either.
         @pl.when(p_i < na_ref[q_i])
         def _scan():
-            # Eq. 6, float op order of core.scan.blocksoa_scan (bit parity)
-            res_term = (res_ref[...].astype(jnp.float32)
-                        * res_scale_ref[0, 0])
+            res_scale, rq = res_scale_ref[0, 0], rq_ref[0, 0]
+            keep_q = keep_ref[0, 0] != 0
             if has_coords:
-                scale = scale_ref[0, 0]
-                d = _sq_dist_int(zq_ref, coords_ref).astype(jnp.float32) \
-                    * (scale * scale)
-                d = d + res_term + rq_ref[0, 0]
-            else:
-                d = res_term + rq_ref[0, 0]
+                zq, scale = zq_ref[...], scale_ref[0, 0]
             if has_sketch:
-                sk_scale = sk_scale_ref[0, 0]
-                d = d + _sq_dist_int(sq_ref, sketch_ref).astype(
-                    jnp.float32) * (sk_scale * sk_scale)
-            # in-situ predicate: validity ∧ liveness/tag/ts ∧ envelope
-            keep = jnp.logical_and(mask_ref[...] != 0, keep_ref[0, 0] != 0)
-            d = jnp.where(keep, d, jnp.float32(NEG_BIG))
-            _merge_tile(d, rows_ref[...], (p_i * n_tiles + j) * BLK_C,
+                sq, sk_scale = sq_ref[...], sk_scale_ref[0, 0]
+            for c in range(n_cols):                  # one lane column each
+                col = slice(c * BLK_C, (c + 1) * BLK_C)
+                # Eq. 6, float op order of core.scan.blocksoa_scan (bit
+                # parity)
+                res_term = res_ref[:, col].astype(jnp.float32) * res_scale
+                if has_coords:
+                    d = _sq_dist_int(zq, coords_ref[:, col]).astype(
+                        jnp.float32) * (scale * scale)
+                    d = d + res_term + rq
+                else:
+                    d = res_term + rq
+                if has_sketch:
+                    d = d + _sq_dist_int(sq, sketch_ref[:, col]).astype(
+                        jnp.float32) * (sk_scale * sk_scale)
+                # in-situ predicate: validity ∧ liveness/tag/ts ∧ envelope
+                keep = jnp.logical_and(mask_ref[:, col] != 0, keep_q)
+                tile_d[c:c + 1, :] = jnp.where(keep, d,
+                                               jnp.float32(NEG_BIG))
+                tile_r[c:c + 1, :] = rows_ref[:, col]
+            _merge_tile(tile_d[...], tile_r[...],
+                        (p_i * n_tiles + j) * (n_cols * BLK_C),
                         best_d, best_r, best_a, width)
 
         last = jnp.logical_and(p_i == pl.num_programs(1) - 1,
@@ -251,7 +292,7 @@ def fused_scan_select(gids, zq, rq, keep, coords, res, mask, rows, scale,
       rq     [Q, P] f32    — dequantized query residual energies
       keep   [Q, P] bool   — envelope-filter verdict (False kills the grain)
       coords [G, k, cap] i16 — the FULL stacked Block-SoA panel tier (only
-                               probed [k, BLK_C] tiles are ever streamed)
+                               probed [k, T] tiles are ever streamed)
       res    [G, cap] i32, mask [G, cap] bool (validity ∧ extra predicates),
       rows   [G, cap] i32 (payload row ids), scale/res_scale [G] f32.
       ``zq=None, coords=None``: leave the coordinate term out (the
@@ -315,7 +356,7 @@ def _select_call(*, gids, zq, rq, keep, sq, tenant_ix, n_active, coords,
                  res, mask, rows, scale, res_scale, sketch, sketch_scale,
                  g_n: int, width: int, interpret: bool):
     """One pallas_call over a query chunk; the shared panels arrive padded
-    to whole cap tiles (and the mask tenant-flattened)."""
+    to whole lane columns (and the mask tenant-flattened)."""
     has_coords = coords is not None
     q_n, p_n = gids.shape
     capp = res.shape[1]
@@ -329,8 +370,10 @@ def _select_call(*, gids, zq, rq, keep, sq, tenant_ix, n_active, coords,
         mgids = gids.reshape(q_n * p_n)
     gids = gids.reshape(q_n * p_n)
     w_pad = _round_up(max(width, 1), 128)      # lane-aligned carry width
+    tile = select_tile(capp, zq.shape[2] if has_coords else 0,
+                       sq.shape[2] if sketch is not None else 0)
 
-    grid = (q_n, p_n, capp // BLK_C)
+    grid = (q_n, p_n, capp // tile)
 
     # Block index maps: scalar-prefetched gids turn (q, p) into the probed
     # grain's HBM offset — affine streaming, no gather anywhere.  The mask
@@ -346,7 +389,7 @@ def _select_call(*, gids, zq, rq, keep, sq, tenant_ix, n_active, coords,
     def per_probe(q, p, j, g, mg, na):          # query-side [.., n, 1] rows
         return (q, _pc(p, q, na), 0, 0)
 
-    def grain_tile(q, p, j, g, mg, na):         # probed grain's cap tile j
+    def grain_tile(q, p, j, g, mg, na):         # probed grain's tile j
         return (g[q * p_n + _pc(p, q, na)], 0, j)
 
     def mask_tile(q, p, j, g, mg, na):
@@ -367,12 +410,12 @@ def _select_call(*, gids, zq, rq, keep, sq, tenant_ix, n_active, coords,
         in_specs.append(pl.BlockSpec((None, None, s_dim, 1), per_probe))
         args.append(sq[..., None])
     if has_coords:
-        in_specs.append(pl.BlockSpec((None, k, BLK_C), grain_tile))
+        in_specs.append(pl.BlockSpec((None, k, tile), grain_tile))
         args.append(coords)
     in_specs += [
-        pl.BlockSpec((None, 1, BLK_C), grain_tile),
-        pl.BlockSpec((None, 1, BLK_C), mask_tile),
-        pl.BlockSpec((None, 1, BLK_C), grain_tile),
+        pl.BlockSpec((None, 1, tile), grain_tile),
+        pl.BlockSpec((None, 1, tile), mask_tile),
+        pl.BlockSpec((None, 1, tile), grain_tile),
     ]
     args += [res[:, None, :], mask[:, None, :].astype(jnp.int32),
              rows[:, None, :]]
@@ -382,7 +425,7 @@ def _select_call(*, gids, zq, rq, keep, sq, tenant_ix, n_active, coords,
     in_specs.append(pl.BlockSpec((None, 1, 1), grain_scalar))
     args.append(res_scale[:, None, None])
     if sketch is not None:
-        in_specs += [pl.BlockSpec((None, s_dim, BLK_C), grain_tile),
+        in_specs += [pl.BlockSpec((None, s_dim, tile), grain_tile),
                      pl.BlockSpec((None, 1, 1), grain_scalar)]
         args += [sketch, sketch_scale[:, None, None]]
 
@@ -398,6 +441,8 @@ def _select_call(*, gids, zq, rq, keep, sq, tenant_ix, n_active, coords,
             pltpu.VMEM((1, w_pad), jnp.float32),   # carried set: dists
             pltpu.VMEM((1, w_pad), jnp.int32),     # carried set: rows
             pltpu.VMEM((1, w_pad), jnp.int32),     # carried set: arrival
+            pltpu.VMEM((tile // BLK_C, BLK_C), jnp.float32),  # step: dists
+            pltpu.VMEM((tile // BLK_C, BLK_C), jnp.int32),    # step: rows
         ],
     )
     kernel = _make_select_kernel(has_coords, sketch is not None, width)

@@ -220,21 +220,78 @@ def _assert_select_equal(got, want):
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
 
-@pytest.mark.parametrize("width", [1, 7, 20, 130, 300])
-@pytest.mark.parametrize("sketch", [0, 3])
-@pytest.mark.parametrize("ragged", [False, True])
-@pytest.mark.parametrize("ties", [True, False])
-def test_fused_select_tie_order_matches_oracle(width, sketch, ragged, ties):
+# (ties, ragged, sketch, width, cap, budget): cap 200 pads to one
+# two-column step a grain; the tilings add a one-column grain (cap 128), a
+# three-column step (cap 300 -> 384 lanes), and a VMEM budget that fits one
+# column only, so each grain of cap 300 takes three grid steps.
+_TIE_CASES = [
+    pytest.param(ties, ragged, sketch, width, 200, None,
+                 id=f"{ties}-{ragged}-{sketch}-{width}")
+    for ties in (True, False) for ragged in (False, True)
+    for sketch in (0, 3) for width in (1, 7, 20, 130, 300)
+] + [
+    pytest.param(True, True, 3, width, cap, budget,
+                 id=f"{name}-{width}")
+    for name, cap, budget in (("grain128", 128, None),
+                              ("cols3", 300, None),
+                              ("split3", 300, 50_000))
+    for width in (20, 130)
+]
+
+
+@pytest.mark.parametrize("ties,ragged,sketch,width,cap,budget", _TIE_CASES)
+def test_fused_select_tie_order_matches_oracle(monkeypatch, ties, ragged,
+                                               sketch, width, cap, budget):
     """The kernel's carried-set select keeps lax.top_k's order, earliest
     (probe, slot) first on equal distances, so the interpreted kernel and
     the jnp two-stage oracle ("fused_ref") agree bit for bit — widths below
-    a tile, at the pool, and over several lane tiles."""
+    a lane column, at the pool, and over several columns; a grain scored in
+    one grid step or, under a small VMEM budget, in several."""
     from repro.core.scan import blocksoa_select_ref
-    from repro.kernels.fused_select import fused_scan_select
-    args, n_active = _select_args(width + sketch, s=sketch, ties=ties)
+    from repro.kernels import fused_select as kfsel
+    args, n_active = _select_args(width + sketch, cap=cap, s=sketch,
+                                  ties=ties)
     kw = dict(n_active=n_active) if ragged else {}
-    got = fused_scan_select(*args, width=width, interpret=True, **kw)
-    _assert_select_equal(got, blocksoa_select_ref(*args, width=width, **kw))
+    want = blocksoa_select_ref(*args, width=width, **kw)
+    capp = -(-cap // 128) * 128
+    select = kfsel.fused_scan_select
+    if budget is not None:
+        monkeypatch.setattr(kfsel, "TILE_VMEM_BYTES", budget)
+        select = select.__wrapped__      # traced anew under the budget
+    assert kfsel.select_tile(capp, 4, sketch) == (capp if budget is None
+                                                  else 128)
+    got = select(*args, width=width, interpret=True, **kw)
+    _assert_select_equal(got, want)
+
+
+@pytest.mark.parametrize("capp,k,s,want", [
+    (128, 32, 8, 128), (2816, 32, 8, 2816), (2944, 32, 8, 2944),
+    (128, 0, 0, 128), (384, 4, 3, 384), (2944, 0, 8, 2944),
+    (1 << 16, 32, 8, 1 << 14), (1 << 17, 64, 0, 1 << 14)])
+def test_select_tile_takes_the_widest_run_that_fits(capp, k, s, want):
+    """A grid step's lane width is a multiple of 128 that divides the padded
+    capacity and whose double-buffered blocks fit the VMEM budget: the whole
+    grain at the benchmark's shapes (k=32, s=8, cohere cap 2,816, openai
+    2,944), one column for a one-column cap, and the widest dividing run
+    for caps too wide for one step."""
+    from repro.kernels import fused_select as kfsel
+    t = kfsel.select_tile(capp, k, s)
+    assert t == want
+    assert t % 128 == 0 and capp % t == 0
+    per_lane = 2 * (2 * -(-k // 16) * 16 + -(-s // 32) * 32 + 96) + 8
+    assert t * per_lane <= kfsel.TILE_VMEM_BYTES
+
+
+def test_select_tile_splits_a_grain_under_a_small_budget(monkeypatch):
+    """Blocks that overflow the budget split the grain into the widest
+    dividing run of columns that fits; nothing fits -> one column."""
+    from repro.kernels import fused_select as kfsel
+    per_lane = 2 * (2 * 32 + 32 + 96) + 8             # k=32, s=8: 392 B
+    monkeypatch.setattr(kfsel, "TILE_VMEM_BYTES", 11 * 128 * per_lane)
+    assert kfsel.select_tile(2816, 32, 8) == 11 * 128  # 22 columns: 2 steps
+    assert kfsel.select_tile(2944, 32, 8) == 128       # 23 columns: prime
+    monkeypatch.setattr(kfsel, "TILE_VMEM_BYTES", 1)
+    assert kfsel.select_tile(2816, 32, 8) == 128
 
 
 def test_fused_select_without_coords_matches_zero_panel():

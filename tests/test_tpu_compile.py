@@ -28,6 +28,15 @@ Q, P, WIDTH = 64, 16, 20
 # The largest batch one kernel call takes (its flat prefetch streams fill
 # half of SMEM), and a batch twice that, which is split along queries.
 SMEM_BATCHES = [(PREFETCH_ENTRIES // P, P), (1024, 128)]
+# The benchmark's calls (q, p, cap, G): cohere768-1m and openai1536-500k
+# 64-query batches, and the cohere serial call; one grid step a grain.
+BENCH_CALLS = [(64, 192, 2816, 512), (64, 96, 2944, 256), (1, 192, 2816, 512)]
+# A cap whose blocks overflow the VMEM budget: two grid steps a grain.
+SPLIT_CALL = (8, 4, 32768, 8)
+SELECT_CALLS = ([pytest.param(q, p, CAP, G, id=f"{q}x{p}")
+                 for q, p in SMEM_BATCHES]
+                + [pytest.param(q, p, cap, g, id=f"{q}x{p}-cap{cap}")
+                   for q, p, cap, g in BENCH_CALLS + [SPLIT_CALL]])
 
 
 @pytest.fixture(scope="module")
@@ -57,17 +66,17 @@ def _compile(fn, args, sharding):
     return text
 
 
-def _select_shapes(q, p, *, sketch=False):
+def _select_shapes(q, p, *, sketch=False, cap=CAP, g=G):
     sd = jax.ShapeDtypeStruct
     a = [sd((q, p), jnp.int32), sd((q, p, K), jnp.int32),
          sd((q, p), jnp.float32), sd((q, p), jnp.bool_),
-         sd((G, K, CAP), jnp.int16),
-         sd((G, CAP), jnp.int32), sd((G, CAP), jnp.bool_),
-         sd((G, CAP), jnp.int32), sd((G,), jnp.float32),
-         sd((G,), jnp.float32)]
+         sd((g, K, cap), jnp.int16),
+         sd((g, cap), jnp.int32), sd((g, cap), jnp.bool_),
+         sd((g, cap), jnp.int32), sd((g,), jnp.float32),
+         sd((g,), jnp.float32)]
     if sketch:
-        a += [sd((q, p, S), jnp.int32), sd((G, S, CAP), jnp.int8),
-              sd((G,), jnp.float32)]
+        a += [sd((q, p, S), jnp.int32), sd((g, S, cap), jnp.int8),
+              sd((g,), jnp.float32)]
     return a
 
 
@@ -94,13 +103,16 @@ def test_fused_scan_select_compiles(one_chip, variant):
     _compile(fn, args, one_chip)
 
 
-@pytest.mark.parametrize("q,p", SMEM_BATCHES,
-                         ids=[f"{q}x{p}" for q, p in SMEM_BATCHES])
-def test_fused_scan_select_compiles_at_smem_bound(one_chip, q, p):
+@pytest.mark.parametrize("q,p,cap,g", SELECT_CALLS)
+def test_fused_scan_select_compiles_at_smem_bound(one_chip, q, p, cap, g):
     """The scalar-prefetch streams stay inside v5e's 1 MiB SMEM: one call
-    at the bound, and a batch past it split into several calls."""
+    at the bound, and a batch past it split into several calls.  The
+    benchmark's calls, each grain's whole capacity one grid step (its
+    blocks in VMEM), and a grain too wide for one step, compile to one
+    kernel call each."""
     text = _compile(lambda *a: fused_scan_select(*a, width=WIDTH),
-                    _select_shapes(q, p, sketch=True), one_chip)
+                    _select_shapes(q, p, sketch=True, cap=cap, g=g),
+                    one_chip)
     calls = -(-q * p // PREFETCH_ENTRIES)
     assert text.count("custom_call_target=\"tpu_custom_call\"") == calls
 
